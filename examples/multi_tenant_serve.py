@@ -25,8 +25,9 @@ def main():
     lm_params = api.init(lm_cfg, jax.random.PRNGKey(0))
 
     # One fleet: two edge tenants + one LM tenant.  machine_model="auto"
-    # (the default) calibrates the planner to THIS host so budgets are
-    # meaningful; engines are quantized + calibrated + jitted behind build.
+    # (the default) fits the planner to the CPU interpreter, or takes the
+    # chip's stock constants on a TPU, so budgets are meaningful; engines
+    # are quantized + calibrated + jitted behind build.
     dep = Deployment.build(
         ["jet_tagger", "tau_select", lm_cfg],
         lm_params={lm_cfg.name: (lm_cfg, lm_params)},

@@ -154,7 +154,9 @@ def cmd_plan(argv: list[str] | None = None) -> int:
                     help="PL DSP-equivalents per layer for the LARE decision")
     ap.add_argument("--machine-model", default=None, metavar="MODEL_JSON",
                     help="fitted MachineModel artifact (python -m repro "
-                         "characterize), 'auto' for the host calibration, "
+                         "characterize), 'auto' for the device's model "
+                         "(CPU interpreter fit, or the chip's stock "
+                         "constants), "
                          "or 'quick'/'full' to characterize inline")
     ap.add_argument("--out", default="plans",
                     help="directory for the JSON artifacts")
@@ -223,7 +225,8 @@ def _deploy_parser(prog: str, description: str) -> argparse.ArgumentParser:
                     help="add an LM tenant (smoke config, seed weights), "
                          "e.g. qwen2_5_3b")
     ap.add_argument("--machine-model", default="auto",
-                    help="'auto' (host calibration, default), 'stock', "
+                    help="'auto' (default: the CPU interpreter's fit, or "
+                         "the chip's stock constants on a TPU), 'stock', "
                          "'quick'/'full' (characterize inline), or a "
                          "MachineModel artifact path")
     ap.add_argument("--batch", type=int, default=None)
@@ -259,7 +262,8 @@ def _serve_smoke(dep, *, iters: int, requests: int = 3) -> dict:
                                  lm_requests=requests)
     report = workload.replay(router, trace, inputs=inputs)
     bad = [r for r in report.records if r.status != "ok"]
-    assert not bad, f"smoke replay left non-ok requests: {bad[:3]}"
+    if bad:
+        raise RuntimeError(f"smoke replay left non-ok requests: {bad[:3]}")
     return router.report()
 
 
@@ -730,6 +734,8 @@ _SUBCOMMANDS = {
 
 
 def main(argv: list[str] | None = None) -> int:
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     # Dispatch by hand (no parse_known_args): the root parser must not
     # swallow `--help` meant for a subcommand — `python -m repro plan

@@ -119,7 +119,8 @@ class Deployment:
         ``ModelConfig``s (LM arch ids resolve to their smoke config).
         ``machine_model`` — see :class:`~repro.deploy.stages.
         CharacterizeStage`: ``"auto"`` (default) calibrates the planner to
-        this host, ``None`` keeps stock constants, ``"quick"``/``"full"``
+        the CPU interpreter, or takes the chip's stock constants on a TPU,
+        ``None`` keeps stock constants, ``"quick"``/``"full"``
         run the characterization sweep, or pass a ``MachineModel``/path.
         ``plan`` — a committed plan artifact (path, ``DeploymentPlan`` or
         ``FleetPlan``): skips characterize+plan and serves it as-is.

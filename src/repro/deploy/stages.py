@@ -141,9 +141,11 @@ class CharacterizeStage:
     Spec values:
 
     * ``None`` / ``"stock"`` — hand-tuned ``hw.py`` constants (skip);
-    * ``"auto"`` — the fast host calibration
+    * ``"auto"`` — on the CPU, the fast host calibration
       (:func:`repro.plan.calibrated_cpu_model`, memoized per process): the
-      gemm term fitted to THIS host so planned-vs-measured is meaningful;
+      gemm term fitted to the Pallas interpreter; on a TPU, the stock
+      constants of that chip (:func:`repro.hw.device_model`, which raises
+      for a device kind it does not know);
     * ``"quick"`` / ``"full"`` — the full characterization sweep at that
       density (``repro.characterize.characterize``, memoized per sweep;
       loaded from ``<artifact_dir>/machine_model.json`` when one exists);
@@ -184,6 +186,11 @@ class CharacterizeStage:
             return done(spec, cached=True,
                         detail=f"caller-supplied {spec.version[:12]}")
         if spec == "auto":
+            import jax
+            device = jax.devices()[0]
+            if device.platform != "cpu":
+                return done(hwlib.device_model(device), cached=True,
+                            detail=f"stock {device.device_kind} constants")
             from repro.plan import calibrate
             cached = calibrate.cpu_model_memoized(batch=ctx.batch or 8)
             model = calibrate.calibrated_cpu_model(batch=ctx.batch or 8)

@@ -191,3 +191,20 @@ def _ceil_to(x: int, q: int) -> int:
 TPU_V5E = TpuV5e()
 AIE_ML = AieMl()
 PL_FABRIC = PlFabric()
+
+# Stock models of the chips the planner knows, keyed by the device kind JAX
+# reports (``jax.devices()[0].device_kind``).
+DEVICE_MODELS = {"TPU v5 lite": TPU_V5E}
+
+
+def device_model(device) -> TpuV5e:
+    """The stock machine model of a JAX ``device``.  A platform or device
+    kind with no entry in :data:`DEVICE_MODELS` raises: planning a chip
+    under another chip's constants would be silently wrong."""
+    model = (DEVICE_MODELS.get(device.device_kind)
+             if device.platform == "tpu" else None)
+    if model is None:
+        raise ValueError(
+            f"no machine model for {device.platform} device kind "
+            f"{device.device_kind!r} (known: {sorted(DEVICE_MODELS)})")
+    return model

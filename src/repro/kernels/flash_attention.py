@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANE = 128
 
@@ -152,7 +150,7 @@ def flash_attention(
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running denom
             pltpu.VMEM((block_q, d), jnp.float32),       # running numerator
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,9 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On CPU (this container) every kernel executes in Pallas ``interpret=True``
-mode, which runs the kernel body in Python for correctness; on a real TPU the
-same call sites compile to Mosaic.  ``use_interpret()`` picks automatically;
-tests force it explicitly so intent is visible.
+On the CPU every kernel executes in Pallas ``interpret=True`` mode, which
+runs the kernel body for correctness only; on a TPU the same call sites
+compile to Mosaic.  ``use_interpret()`` picks from the backend and refuses
+any other platform; tests force it explicitly so intent is visible.
 """
 
 from __future__ import annotations
@@ -21,7 +21,16 @@ from repro.kernels import tiled_gemm as _tg
 
 
 def use_interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    """True on the CPU (the interpreter the tests run), False on a TPU.
+    Any other backend raises: these kernels are written for Mosaic, and
+    neither compiling them for another target nor interpreting them there
+    would say anything about the chip."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def tiled_gemm(x, w, **kw):

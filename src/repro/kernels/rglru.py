@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _scan_kernel(a_ref, b_ref, o_ref, h_ref, *, block_t: int):
     ti = pl.program_id(1)
@@ -74,7 +72,7 @@ def linear_scan(
         out_specs=pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, tp, d), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

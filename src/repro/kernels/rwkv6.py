@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
                   block_t: int):
@@ -86,7 +84,7 @@ def rwkv6_scan(
         out_specs=pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tp, d), r.dtype),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -12,14 +12,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with explicit-Auto axis types where the jax version
-    has them (0.5+); older jax has neither `AxisType` nor the kwarg, and its
-    meshes are Auto-only anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis typed Auto (sharding propagated by
+    the compiler, as the model code's `with_sharding_constraint`s expect)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.models.config import ModelConfig, MoEConfig
 from repro.models.layers import dense_init, dtype_of
 from repro import sharding as shlib
@@ -248,7 +247,7 @@ def _moe_a2a(p: dict, x: jax.Array, cfg: ModelConfig):
         return (out.astype(xl.dtype).reshape(bl, sl, d),
                 jax.lax.pmean(aux, ep_axes))
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=(x_spec, w_spec), out_specs=(x_spec, P()),
         check_vma=False,
     )(x, {k: p[k] for k in w_spec})
@@ -323,7 +322,7 @@ def moe_block(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
                 return (jax.lax.psum(y, "model"),
                         jax.lax.psum(aux, "model") / model_n)
 
-            y, aux = compat.shard_map(
+            y, aux = jax.shard_map(
                 _ep, mesh=mesh,
                 in_specs=(x_spec, w_spec),
                 out_specs=(x_spec, P()),
@@ -345,7 +344,7 @@ def moe_block(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
                                    gather_axes=tuple(fsdp or ()))
                 return jax.lax.psum(y, "model"), aux
 
-            y, aux = compat.shard_map(
+            y, aux = jax.shard_map(
                 _tp, mesh=mesh,
                 in_specs=(x_spec, w_spec),
                 out_specs=(x_spec, P()),

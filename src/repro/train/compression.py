@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 F32 = jnp.float32
 
 
@@ -102,7 +100,7 @@ def build_manual_dp_step(loss_fn: Callable, opt, mesh: Mesh, *,
         rep = jax.tree.map(lambda _: P(), state["params"])
         rep_opt = jax.tree.map(lambda _: P(), state["opt"])
         res_spec = jax.tree.map(lambda _: P(dp_axis), state["residual"])
-        new_params, new_opt, new_res = compat.shard_map(
+        new_params, new_opt, new_res = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(rep, rep_opt, P(), res_spec, n_batch_dims),
             out_specs=(rep, rep_opt, res_spec),

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def pipeline_apply(layer_fn: Callable, stacked_params, x, *, mesh: Mesh,
                    axis: str = "pod", microbatches: int | None = None):
@@ -86,7 +84,7 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x, *, mesh: Mesh,
         return outs.reshape(x_all.shape)
 
     p_spec = jax.tree.map(lambda _: P(axis), stacked_params)
-    return compat.shard_map(
+    return jax.shard_map(
         staged, mesh=mesh, in_specs=(p_spec, P()), out_specs=P(),
         check_vma=False,
     )(stacked_params, x)

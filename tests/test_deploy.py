@@ -1,5 +1,7 @@
 """repro.deploy facade: staged pipeline, caching, serving, bench, CLI."""
 
+import pathlib
+
 import numpy as np
 import pytest
 
@@ -170,6 +172,75 @@ def test_artifact_dir_writes_plan(tmp_path):
     assert plan_lib.DeploymentPlan.load(art).layers == dep.plan.layers
 
 
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,expected", [
+    ("cpu", "cpu", "host fit"),
+    ("tpu", "TPU v5 lite", "stock v5e"),
+    ("tpu", "TPU v9 unknown", ValueError),
+    ("gpu", "NVIDIA H100", ValueError),
+])
+def test_auto_machine_model_follows_the_device(platform, kind, expected,
+                                              monkeypatch):
+    """``"auto"`` on the CPU keeps the interpreter's host fit; on a TPU it
+    is that chip's stock constants, looked up by device kind, and a kind
+    with no model raises instead of borrowing another chip's numbers."""
+    import jax
+
+    from repro import hw as hwlib
+    if platform != "cpu":
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a, **k: [_Device(platform, kind)])
+    ctx = StageContext(machine_model="auto", cache=plan_lib.PlanCache())
+    if expected is ValueError:
+        with pytest.raises(ValueError, match=kind):
+            stages.CharacterizeStage().run(ctx)
+        return
+    stages.CharacterizeStage().run(ctx)
+    if expected == "stock v5e":
+        assert ctx.model is hwlib.TPU_V5E
+        assert ctx.plan_kw["tpu"].hbm_bw == 819e9
+    else:
+        assert isinstance(ctx.model, hwlib.TpuV5e)
+        assert ctx.model.hbm_bw == 1e15          # the interpreter's fit
+        assert ctx.model.kernel_overhead_s != hwlib.TPU_V5E.kernel_overhead_s
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(env_dir, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; unset, the cache goes to
+    the fixed in-checkout directory.  Every executable is kept."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro import runtime
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(runtime.COMPILE_CACHE_DIR)
+        else:
+            want = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+            # JAX reads the variable when it starts; stand in for that.
+            jax.config.update("jax_compilation_cache_dir", want)
+        assert runtime.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        assert runtime.COMPILE_CACHE_DIR.parent == \
+            pathlib.Path(__file__).resolve().parents[1]
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 def test_stage_context_individually_invokable():
     """The stages are usable without Deployment: a hand-built context run
     through PlanStage alone is the documented plan-only pipeline."""
@@ -188,7 +259,15 @@ def test_stage_context_individually_invokable():
 # Unified CLI
 # ---------------------------------------------------------------------------
 
-def test_cli_plan_subcommand(tmp_path, capsys):
+@pytest.fixture
+def no_cache_side_effect(monkeypatch):
+    """``cli.main`` turns on the persistent compile cache for its process;
+    these tests share a worker with others and must not."""
+    from repro import runtime
+    monkeypatch.setattr(runtime, "enable_compile_cache", lambda: "")
+
+
+def test_cli_plan_subcommand(tmp_path, capsys, no_cache_side_effect):
     from repro import cli
     rc = cli.main(["plan", "qubit", "--target", "tpu",
                    "--out", str(tmp_path)])
@@ -198,7 +277,7 @@ def test_cli_plan_subcommand(tmp_path, capsys):
     assert (tmp_path / "qubit_tpu.json").exists()
 
 
-def test_cli_deploy_dry_run(tmp_path, capsys):
+def test_cli_deploy_dry_run(tmp_path, capsys, no_cache_side_effect):
     from repro import cli
     rc = cli.main(["deploy", "jet_tagger", "--dry-run",
                    "--machine-model", "stock", "--out", str(tmp_path)])

@@ -146,9 +146,7 @@ def test_analyzer_counts_collectives_with_groups():
         return jax.lax.psum(x, "data")
 
     x = jnp.ones((8, 128))
-    from repro import compat
-    txt = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-
+    txt = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                                 check_vma=False)).lower(x).compile().as_text()
     r = analyze_hlo(txt)
     # group size 1: wire bytes 0, but op counted

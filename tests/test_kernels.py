@@ -15,6 +15,18 @@ def randn(shape, dtype=jnp.float32, scale=1.0):
     return jnp.asarray(RNG.normal(size=shape) * scale, dtype)
 
 
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", RuntimeError)])
+def test_use_interpret_by_backend(backend, interpret, monkeypatch):
+    """Interpreted on the CPU, compiled on a TPU, refused anywhere else."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is RuntimeError:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.use_interpret()
+    else:
+        assert ops.use_interpret() is interpret
+
+
 # ---------------------------------------------------------------------------
 # tiled_gemm
 # ---------------------------------------------------------------------------

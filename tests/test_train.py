@@ -10,7 +10,7 @@ import pytest
 
 from _hyp import given, settings, st
 
-from repro import compat, configs
+from repro import configs
 from repro.data.pipeline import synth_batch
 from repro.launch.mesh import make_host_mesh
 from repro.models import api
@@ -213,7 +213,7 @@ def test_compressed_psum_roundtrip():
         return compression.compressed_psum(x, "data")
 
     x = jnp.linspace(-3, 3, 64)
-    out = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                                 check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), atol=0.06)
 
@@ -228,7 +228,7 @@ def test_error_feedback_residual_carries_quant_error():
         red, new_e = compression.ErrorFeedback.apply(gg, ee, "data", world=1)
         return red, new_e
 
-    red, new_e = jax.jit(compat.shard_map(
+    red, new_e = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False))(g, e)
     # quantization error is exactly what is carried
