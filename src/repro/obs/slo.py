@@ -188,9 +188,17 @@ class SloMonitor:
         self._in_violation.setdefault(tenant, set())
 
     # -- feeding ----------------------------------------------------------
-    def observe(self, tenant: str, latency_s: float):
+    def observe(self, tenant: str, latency_s: float, trace=None):
         """One completed request.  Unknown tenants and non-finite samples
-        are ignored (the metrics layer already counts poisoned timers)."""
+        are ignored (the metrics layer already counts poisoned timers).
+        With tracing on the call is an ``slo.observe`` span carrying the
+        request id ``trace``."""
+        if not self.tracer.enabled:
+            return self._observe(tenant, latency_s)
+        with self.tracer.span("slo.observe", trace=trace, tenant=tenant):
+            self._observe(tenant, latency_s)
+
+    def _observe(self, tenant: str, latency_s: float):
         b = self.budgets.get(tenant)
         if b is None or not math.isfinite(latency_s):
             return

@@ -8,12 +8,20 @@ cache-hit flags).  A :class:`Tracer` is an append-only, bounded span sink
 that the serving runtime (:mod:`repro.serve`), the deployment stages
 (:mod:`repro.deploy`) and the characterization harness all emit into.
 
+An enabled tracer's :meth:`Tracer.span` also opens a
+``jax.profiler.TraceAnnotation`` of the same name for the span's extent.
+With no profiler session running that costs about a microsecond; while one
+runs, every program span lands in its ``.xplane.pb`` on the emitting thread,
+on the same clock as the device's operations, so an idle gap on the device
+can be put down to the innermost span open on the host.  Spans recorded
+with :meth:`Tracer.add` after the fact stay in memory only.
+
 Overhead discipline: every emit site in a hot path guards on
 ``tracer.enabled`` (one attribute read) before doing any work, and the
 shared :data:`NULL_TRACER` used as the default is permanently disabled —
 tracing-off dispatch costs one branch (guarded by a micro-test in
-``tests/test_obs.py``).  No jax imports here: the module must stay cheap to
-import and safe to use from any layer.
+``tests/test_obs.py``).  jax is imported on the first enabled span, not
+here: the module must stay cheap to import and safe to use from any layer.
 """
 
 from __future__ import annotations
@@ -44,22 +52,41 @@ class Span:
                 "trace_id": self.trace_id, "attrs": dict(self.attrs)}
 
 
+_annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class _SpanCtx:
     """Context manager recording one span on exit (exceptions included —
-    a span that died is still time the caller spent)."""
-    __slots__ = ("_tracer", "_name", "_trace", "_attrs", "_t0")
+    a span that died is still time the caller spent), inside a profiler
+    annotation of the same name."""
+    __slots__ = ("_tracer", "_name", "_trace", "_attrs", "_t0", "_ann")
 
     def __init__(self, tracer, name, trace, attrs):
         self._tracer, self._name = tracer, name
         self._trace, self._attrs = trace, attrs
 
     def __enter__(self):
+        self._ann = _trace_annotation()(self._name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.add(self._name, self._t0, time.perf_counter(),
-                         trace=self._trace, **self._attrs)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        tracer = self._tracer
+        if tracer.enabled:
+            tracer._record(self._name, self._t0, t1, self._trace,
+                           self._attrs)
         return False
 
 
@@ -88,14 +115,16 @@ class Tracer:
         self.enabled = enabled
         self.maxlen = maxlen
         self.dropped = 0
-        self._spans: list[Span] = []
+        self._spans: list[tuple] = []     # Span fields, in order
         self._lock = threading.Lock()
         self._trace_ids = itertools.count(1)
 
     # -- emission ---------------------------------------------------------
     def span(self, name: str, *, trace=None, **attrs):
-        """Context manager timing the enclosed block.  With the tracer
-        disabled this returns a shared no-op (no allocation, no clock)."""
+        """Context manager timing the enclosed block, also written into a
+        running profiler trace.  With the tracer disabled this returns a
+        shared no-op (no allocation, no clock); a hot path reads
+        :attr:`enabled` itself first, so it builds no ``attrs`` either."""
         if not self.enabled:
             return _NOOP_CTX
         return _SpanCtx(self, name, trace, attrs)
@@ -104,18 +133,24 @@ class Tracer:
             **attrs) -> None:
         """Record an explicit interval (e.g. queue wait measured between a
         submit and an admit that happen in different call frames)."""
-        if not self.enabled:
-            return
-        s = Span(name=name, t0_s=t0_s, dur_s=max(t1_s - t0_s, 0.0),
-                 trace_id=trace, attrs=attrs)
+        if self.enabled:
+            self._record(name, t0_s, t1_s, trace, attrs)
+
+    def _record(self, name, t0_s, t1_s, trace, attrs) -> None:
+        # A plain tuple in the sink: a frozen Span takes microseconds to
+        # build and keeps the collector busy, so :attr:`spans` builds them
+        # when read, off the serving path.
+        rec = (name, t0_s, max(t1_s - t0_s, 0.0), trace, attrs)
         with self._lock:
             if len(self._spans) >= self.maxlen:
                 self.dropped += 1
                 return
-            self._spans.append(s)
+            self._spans.append(rec)
 
     def next_trace_id(self) -> int:
-        """A fresh per-tracer trace id (for callers without a request id)."""
+        """A fresh per-tracer trace id: the edge path's request id, drawn
+        once per ``Router.infer`` (or per ``EdgeEngine.infer`` called
+        directly) and carried by every span of that request."""
         return next(self._trace_ids)
 
     # -- access -----------------------------------------------------------
@@ -123,7 +158,8 @@ class Tracer:
     def spans(self) -> list[Span]:
         """A snapshot copy — safe to iterate while serving continues."""
         with self._lock:
-            return list(self._spans)
+            recs = list(self._spans)
+        return [Span(*r) for r in recs]
 
     def by_trace(self, trace_id) -> list[Span]:
         return [s for s in self.spans if s.trace_id == trace_id]

@@ -595,8 +595,14 @@ class EdgeEngine:
                 params, calib_x=calib_x if calibrate else None, act=cfg.act)
         self.qparams = qparams
         self.x_scale = x_scale
-        self._fwd = jax.jit(lambda x: edge_lib.edge_forward_q8(
-            self.qparams, cfg, x, x_scale=x_scale, plan=self.plan))
+
+        # Named, so the profiler's host ``PjitFunction(edge_forward)``
+        # events and the device's ``jit_edge_forward`` module say which
+        # program ran.
+        def edge_forward(x):
+            return edge_lib.edge_forward_q8(self.qparams, cfg, x,
+                                            x_scale=x_scale, plan=self.plan)
+        self._fwd = jax.jit(edge_forward)
         self._hlo_text: str | None = None
         # Degradation ladder state (repro.serve.resilience): level 0 runs
         # the planned fused megakernel; level 1 the per-layer gemm_int8
@@ -613,10 +619,12 @@ class EdgeEngine:
         """The per-layer (``fused=False``) jit, compiled on first use."""
         if self._fwd_fallback is None:
             from repro.models import edge as edge_lib
-            self._fwd_fallback = jax.jit(
-                lambda x: edge_lib.edge_forward_q8(
+
+            def edge_forward_per_layer(x):
+                return edge_lib.edge_forward_q8(
                     self.qparams, self.cfg, x, x_scale=self.x_scale,
-                    plan=self.plan, fused=False))
+                    plan=self.plan, fused=False)
+            self._fwd_fallback = jax.jit(edge_forward_per_layer)
         return self._fwd_fallback
 
     def degrade(self) -> bool:
@@ -644,7 +652,24 @@ class EdgeEngine:
             self._hlo_text = self._fwd.lower(x).compile().as_text()
         return self._hlo_text
 
-    def infer(self, x) -> jax.Array:
+    def infer(self, x, trace=None) -> jax.Array:
+        """One forward, returned ready and checked finite on the host.
+
+        With tracing on, the call is an ``infer`` span holding
+        ``engine.dispatch`` (argument handling, the copy of a host input
+        to the device, the enqueue; ``h2d_bytes``), ``engine.wait``
+        (launch and forward) and ``engine.readback`` (the copy to the host
+        and the finiteness check; ``d2h_bytes``).  They carry ``trace``,
+        the request id, drawn from the tracer when the caller has none."""
+        tracer = self.tracer
+        if not tracer.enabled:
+            return self._infer(x, None, None)
+        if trace is None:
+            trace = tracer.next_trace_id()
+        with tracer.span("infer", trace=trace, tenant=self.trace_label):
+            return self._infer(x, tracer, trace)
+
+    def _infer(self, x, tracer, trace) -> jax.Array:
         t0 = time.perf_counter()
         spec = None
         if self.injector is not None:
@@ -658,28 +683,39 @@ class EdgeEngine:
                 time.sleep(spec.magnitude_s)   # inside [t0, t1]: visible
         fwd = self._fwd if self.degrade_level == 0 else self._fallback()
         # Deliberate sync: infer() returns a ready result by contract.
-        y = jax.block_until_ready(fwd(x))  # repro: check-ok(lint.host-sync)
+        if tracer is None:
+            y = jax.block_until_ready(fwd(x))  # repro: check-ok(lint.host-sync)
+        else:
+            label = self.trace_label
+            h2d = 0 if isinstance(x, jax.Array) else x.nbytes
+            with tracer.span("engine.dispatch", trace=trace, tenant=label,
+                             h2d_bytes=h2d):
+                y = fwd(x)
+            with tracer.span("engine.wait", trace=trace, tenant=label):
+                y = jax.block_until_ready(y)  # repro: check-ok(lint.host-sync)
         if spec is not None and spec.kind == "non_finite_output":
             y = jnp.full_like(y, jnp.nan)      # poison; caught just below
         # Host-side finiteness guard: np.asarray on a ready CPU array is
         # zero-copy, and the reduction is microseconds next to the forward.
         # A poisoned output FAILS the call rather than returning garbage.
-        if not bool(np.isfinite(np.asarray(y)).all()):  # repro: check-ok(lint.host-sync)
+        if tracer is None:
+            finite = bool(np.isfinite(np.asarray(y)).all())  # repro: check-ok(lint.host-sync)
+        else:
+            with tracer.span("engine.readback", trace=trace, tenant=label,
+                             d2h_bytes=y.nbytes):
+                finite = bool(np.isfinite(np.asarray(y)).all())  # repro: check-ok(lint.host-sync)
+        if not finite:
             t1 = time.perf_counter()
             self.faults += 1
-            if self.tracer.enabled:
-                self.tracer.add("fault/non_finite", t0, t1,
-                                tenant=self.trace_label)
+            if tracer is not None:
+                tracer.add("fault/non_finite", t0, t1, trace=trace,
+                           tenant=self.trace_label)
             raise NonFiniteOutput(
                 f"{self.trace_label}: non-finite model output")
-        t1 = time.perf_counter()
-        dt = t1 - t0
+        dt = time.perf_counter() - t0
         self.total_s += dt
         self.calls += 1
         self._latencies.append(dt)
-        if self.tracer.enabled:
-            self.tracer.add("infer", t0, t1, trace=self.calls,
-                            tenant=self.trace_label)
         return y
 
     def span_stats(self) -> dict:

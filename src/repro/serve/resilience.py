@@ -182,16 +182,19 @@ class Supervisor:
         """Breaker gate; ``False`` means refuse (map to TenantBreakerOpen)."""
         return self.breaker(net_id).allow()
 
-    def call_edge(self, tenant, x):
+    def call_edge(self, tenant, x, trace=None):
         """Run a sync edge inference with bounded retry-with-backoff.
         Non-finite outputs are deterministic (same input, same NaN) and
-        are not retried; anything else is treated as transient."""
+        are not retried; anything else is treated as transient.  ``trace``
+        is the request id every attempt's engine spans carry."""
         cfg = self.cfg(tenant.net_id)
         attempts = max(1, int(cfg.get("retries", 0)) + 1)
         backoff = float(cfg.get("backoff_s", 0.0))
         for attempt in range(attempts):
             try:
-                return tenant.engine.infer(x)
+                if trace is None:
+                    return tenant.engine.infer(x)
+                return tenant.engine.infer(x, trace=trace)
             except NonFiniteOutput:
                 raise
             except Exception:
@@ -202,7 +205,11 @@ class Supervisor:
                 if backoff > 0.0:
                     time.sleep(backoff * (2 ** attempt))
 
-    def record_success(self, tenant, dt_s: float | None = None) -> None:
+    def record_success(self, tenant, dt_s: float | None = None,
+                       trace=None) -> None:
+        """Book one completed request: the breaker, the deadline (a
+        ``fault/deadline`` span carrying the request id ``trace``) and the
+        ladder's restore streak."""
         nid = tenant.net_id
         br = self.breaker(nid)
         was_recovering = br.state != CLOSED
@@ -215,7 +222,8 @@ class Supervisor:
                 if self.tracer.enabled:
                     now = time.perf_counter()
                     self.tracer.add("fault/deadline", now - dt_s, now,
-                                    tenant=nid, deadline_s=deadline)
+                                    trace=trace, tenant=nid,
+                                    deadline_s=deadline)
         self._streak[nid] = self._streak.get(nid, 0) + 1
         # ladder restore: a clean streak at the degraded level (one
         # breaker-cooldown's worth, after the probe that reclosed) earns

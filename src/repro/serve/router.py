@@ -78,9 +78,12 @@ Two further plan-driven controls:
   hook for chaos testing.
 
 Pass ``tracer=`` (a :class:`repro.obs.Tracer`) to thread request-grain
-spans through every tenant engine: edge requests emit ``infer`` +
-``request`` spans, LM requests decompose into ``queue`` / ``prefill_chunk``
-/ ``decode_step`` / ``request`` spans keyed by the request id as trace id.
+spans through every tenant engine: edge requests emit ``request`` around
+``router.admit``, the engine's ``infer`` (``engine.dispatch`` /
+``engine.wait`` / ``engine.readback``) and ``router.account``, all keyed by
+one request id the router draws from the tracer; LM requests decompose
+into ``queue`` / ``prefill_chunk`` / ``decode_step`` / ``request`` spans
+keyed by the request id as trace id.
 ``report()`` attaches each engine's per-kind service-time aggregates under
 ``"spans"`` regardless of tracing, so snapshots carry the decomposition.
 """
@@ -338,7 +341,7 @@ class Router:
                 f"is admitted after {br.cooldown} refusals")
 
     def _record_failure(self, t: Tenant, exc: BaseException,
-                        t0: float | None = None):
+                        t0: float | None = None, trace=None):
         """Book one failed request/tick against its tenant: the failure
         counter, the breaker (when supervised), and a ``fault/<kind>``
         audit span.  Non-finite faults already emitted their span at the
@@ -347,7 +350,7 @@ class Router:
         if self.tracer.enabled and fault_kind(exc) != "non_finite":
             now = time.perf_counter()
             self.tracer.add(f"fault/{fault_kind(exc)}",
-                            t0 if t0 is not None else now, now,
+                            t0 if t0 is not None else now, now, trace=trace,
                             tenant=t.net_id, error=str(exc)[:160])
         if self.supervisor is not None:
             self.supervisor.record_failure(t)
@@ -357,33 +360,65 @@ class Router:
         """Route one edge inference; measured against the tenant's budget.
         A failing engine raises :class:`TenantFaulted` (after the
         supervisor's bounded retries, when one is attached) — the fault is
-        booked against THIS tenant and co-residents are untouched."""
-        t = self.tenant(net_id)
-        self._admission_check(t)
-        self._breaker_gate(t)
+        booked against THIS tenant and co-residents are untouched.
+
+        With tracing on, one request id from the tracer joins the
+        request's spans, nested on the calling thread: ``request`` (the
+        whole call) holds ``router.admit`` (tenant lookup, admission,
+        breaker), the engine's ``infer`` and ``router.account``
+        (metrics, supervisor, ``slo.observe``, ``router.replan_check``)."""
+        tracer = self.tracer
+        if not tracer.enabled:
+            t = self.tenant(net_id)
+            self._admission_check(t)
+            self._breaker_gate(t)
+            y, dt = self._call_edge(t, x, None)
+            self._account(t, dt, None)
+            return y
+        rid = tracer.next_trace_id()
+        with tracer.span("request", trace=rid, tenant=net_id):
+            with tracer.span("router.admit", trace=rid, tenant=net_id):
+                t = self.tenant(net_id)
+                self._admission_check(t)
+                self._breaker_gate(t)
+            y, dt = self._call_edge(t, x, rid)
+            with tracer.span("router.account", trace=rid, tenant=net_id):
+                self._account(t, dt, rid)
+        return y
+
+    def _call_edge(self, t: Tenant, x, rid):
+        """The engine call (through the supervisor when one is attached)
+        and its duration; a failure is booked and raised as
+        :class:`TenantFaulted`.  Only a traced call passes the request id,
+        so an engine with a plain ``infer(x)`` still serves untraced."""
         sup = self.supervisor
         t0 = time.perf_counter()
         try:
-            y = sup.call_edge(t, x) if sup is not None else t.engine.infer(x)
+            if sup is not None:
+                y = sup.call_edge(t, x, trace=rid)
+            elif rid is None:
+                y = t.engine.infer(x)
+            else:
+                y = t.engine.infer(x, trace=rid)
         except Exception as exc:
-            self._record_failure(t, exc, t0)
+            self._record_failure(t, exc, t0, trace=rid)
             raise TenantFaulted(
-                f"tenant {net_id!r} request failed: {exc}") from exc
-        t1 = time.perf_counter()
-        t.metrics.observe_latency(t1 - t0)
-        if sup is not None:
-            sup.record_success(t, t1 - t0)
+                f"tenant {t.net_id!r} request failed: {exc}") from exc
+        return y, time.perf_counter() - t0
+
+    def _account(self, t: Tenant, dt: float, rid):
+        """Book one completed edge request of ``dt`` seconds."""
+        t.metrics.observe_latency(dt)
+        if self.supervisor is not None:
+            self.supervisor.record_success(t, dt, trace=rid)
         if self.slo is not None:
-            self.slo.observe(net_id, t1 - t0)
-        if self.tracer.enabled:
-            # The router-grain envelope around the engine's own ``infer``
-            # span; the engine numbered this call, so reuse its counter as
-            # the trace id and the two spans join on it.
-            self.tracer.add("request", t0, t1,
-                            trace=getattr(t.engine, "calls", None),
-                            tenant=net_id)
-        self._maybe_replan(t)
-        return y
+            self.slo.observe(t.net_id, dt, trace=rid)
+        if rid is None:
+            self._maybe_replan(t)
+        else:
+            with self.tracer.span("router.replan_check", trace=rid,
+                                  tenant=t.net_id):
+                self._maybe_replan(t)
 
     # -- lm path (continuous batching) ------------------------------------
     def submit(self, net_id: str, request):

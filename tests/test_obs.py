@@ -453,6 +453,127 @@ def test_router_report_carries_span_stats():
 
 
 # ---------------------------------------------------------------------------
+# Edge request spans: one id per request, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+EDGE_REQUEST_SPANS = {"request", "router.admit", "infer", "engine.dispatch",
+                      "engine.wait", "engine.readback", "router.account",
+                      "slo.observe", "router.replan_check"}
+
+
+@pytest.fixture(scope="module")
+def traced_fleet():
+    """A traced two-tenant router, supervised and SLO-monitored as
+    ``Deployment.serve`` builds it, warmed; with one host input each."""
+    from repro.obs import SloMonitor
+    cfgs = [edge.edge_config("jet_tagger"), edge.edge_config("tau_select")]
+    fleet = plan_lib.plan_fleet(cfgs, target="tpu",
+                                cache=plan_lib.PlanCache())
+    tr = Tracer()
+    router = Router.from_fleet(
+        fleet, tracer=tr, resilience=True,
+        slo=SloMonitor.from_fleet(fleet, tracer=tr))
+    xs = {c.name: np.random.default_rng(i).standard_normal(
+        (c.batch, c.dims[0])).astype(np.float32)
+        for i, c in enumerate(cfgs)}
+    for nid, x in xs.items():
+        router.infer(nid, x)                      # jit + cache warm
+    tr.clear()
+    return router, tr, xs
+
+
+def test_edge_spans_share_one_request_id(traced_fleet):
+    router, tr, xs = traced_fleet
+    tr.clear()
+    for _ in range(2):
+        for nid, x in xs.items():
+            router.infer(nid, x)
+    ids = {}
+    for s in tr.spans:
+        ids.setdefault(s.trace_id, []).append(s)
+    assert None not in ids and len(ids) == 4
+    for mine in ids.values():
+        names = [s.name for s in mine if "/" not in s.name]  # not audits
+        assert sorted(names) == sorted(EDGE_REQUEST_SPANS)
+        assert len({s.attrs["tenant"] for s in mine}) == 1
+    per_tenant = {}
+    for rid, mine in ids.items():
+        per_tenant.setdefault(mine[0].attrs["tenant"], set()).add(rid)
+    assert set(per_tenant) == set(xs)
+    a, b = per_tenant.values()
+    assert not a & b
+
+
+def test_edge_spans_carry_copy_bytes(traced_fleet):
+    router, tr, xs = traced_fleet
+    nid, x = next(iter(xs.items()))
+    tr.clear()
+    y = router.infer(nid, x)                      # a host input is copied
+    router.infer(nid, jnp.asarray(x))             # a device input is not
+    dispatch = tr.by_name("engine.dispatch")
+    readback = tr.by_name("engine.readback")
+    assert [s.attrs["h2d_bytes"] for s in dispatch] == [x.nbytes, 0]
+    assert [s.attrs["d2h_bytes"] for s in readback] == [y.nbytes] * 2
+    assert y.nbytes == x.shape[0] * edge.edge_config(nid).dims[-1] * 4
+
+
+def test_edge_spans_nest_in_the_profiler_trace(traced_fleet, tmp_path):
+    """Inside the benchmark's ``router.infer`` annotation, the program's
+    spans sit on the serving thread in the profiler's own trace:
+    ``router.admit``, then ``infer`` holding dispatch, wait and readback
+    in that order, then ``router.account`` holding ``slo.observe``."""
+    from chipbench import trace as trace_lib
+    router, tr, xs = traced_fleet
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for nid, x in xs.items():
+            with jax.profiler.TraceAnnotation("router.infer"):
+                np.asarray(router.infer(nid, x))
+    finally:
+        jax.profiler.stop_trace()
+    host = trace_lib.load(tmp_path).serving_thread
+    names = np.asarray(host.names)
+
+    def one(name, lo, hi):
+        """The single event ``name`` inside ``[lo, hi]``."""
+        sel = (names == name) & (host.start >= lo) & (host.end <= hi)
+        assert sel.sum() == 1, (name, int(sel.sum()))
+        k = int(np.flatnonzero(sel)[0])
+        return host.start[k], host.end[k]
+
+    outer = np.flatnonzero(names == "router.infer")
+    assert len(outer) == len(xs)
+    for k in outer:
+        req = one("request", host.start[k], host.end[k])
+        admit = one("router.admit", *req)
+        infer = one("infer", *req)
+        account = one("router.account", *req)
+        assert admit[1] <= infer[0] and infer[1] <= account[0]
+        dispatch = one("engine.dispatch", *infer)
+        wait = one("engine.wait", *infer)
+        readback = one("engine.readback", *infer)
+        assert dispatch[1] <= wait[0] and wait[1] <= readback[0]
+        assert np.any((names == "PjitFunction(edge_forward)")
+                      & (host.start >= dispatch[0])
+                      & (host.end <= dispatch[1]))
+        one("slo.observe", *account)
+        one("router.replan_check", *account)
+
+
+@pytest.mark.parametrize("level, name", [(0, "edge_forward"),
+                                         (1, "edge_forward_per_layer")])
+def test_edge_forward_jits_have_stable_names(traced_fleet, level, name):
+    """The fused forward and the per-layer fallback are named programs, so
+    the profiler's host and device events say which one ran."""
+    router, _, xs = traced_fleet
+    nid, x = next(iter(xs.items()))
+    eng = router.tenant(nid).engine
+    fwd = eng._fwd if level == 0 else eng._fallback()
+    assert fwd.__name__ == name
+    assert f"module @jit_{name} " in fwd.lower(x).as_text()
+
+
+# ---------------------------------------------------------------------------
 # Deployment + stage spans
 # ---------------------------------------------------------------------------
 
