@@ -80,6 +80,11 @@ class _SpanCtx:
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **attrs) -> None:
+        """Add attributes known only inside the span (e.g. an output's
+        size once the call that makes it has returned)."""
+        self._attrs.update(attrs)
+
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         self._ann.__exit__(*exc)
@@ -95,6 +100,9 @@ class _NoopCtx:
 
     def __enter__(self):
         return self
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __exit__(self, *exc):
         return False

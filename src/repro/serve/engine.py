@@ -655,12 +655,19 @@ class EdgeEngine:
     def infer(self, x, trace=None) -> jax.Array:
         """One forward, returned ready and checked finite on the host.
 
+        The copy of the output to the host is requested as soon as the
+        forward is enqueued, so the runtime runs it right behind the
+        forward and the call blocks once, until the output is on the host.
+
         With tracing on, the call is an ``infer`` span holding
         ``engine.dispatch`` (argument handling, the copy of a host input
-        to the device, the enqueue; ``h2d_bytes``), ``engine.wait``
-        (launch and forward) and ``engine.readback`` (the copy to the host
-        and the finiteness check; ``d2h_bytes``).  They carry ``trace``,
-        the request id, drawn from the tracer when the caller has none."""
+        to the device, the enqueue; ``h2d_bytes``, and ``d2h_early_bytes``,
+        the output bytes whose copy is asked for with no wait for the
+        forward before it), ``engine.wait`` (the request for that copy,
+        launch, forward and copy, until the output is on the host) and
+        ``engine.readback`` (the finiteness check on the host copy;
+        ``d2h_bytes``).  They carry ``trace``, the request id, drawn from
+        the tracer when the caller has none."""
         tracer = self.tracer
         if not tracer.enabled:
             return self._infer(x, None, None)
@@ -682,28 +689,33 @@ class EdgeEngine:
             if spec.kind == "latency_spike" and spec.magnitude_s > 0:
                 time.sleep(spec.magnitude_s)   # inside [t0, t1]: visible
         fwd = self._fwd if self.degrade_level == 0 else self._fallback()
-        # Deliberate sync: infer() returns a ready result by contract.
+        # Deliberate sync, the only one (infer() returns a ready result by
+        # contract): np.asarray on the output not yet ready asks for its
+        # copy to the host, which the runtime chains behind the forward.
+        # Waiting for the forward first would add a second round trip
+        # before the copy could start.
         if tracer is None:
-            y = jax.block_until_ready(fwd(x))  # repro: check-ok(lint.host-sync)
+            y = fwd(x)
+            host = np.asarray(y)  # repro: check-ok(lint.host-sync)
         else:
             label = self.trace_label
             h2d = 0 if isinstance(x, jax.Array) else x.nbytes
             with tracer.span("engine.dispatch", trace=trace, tenant=label,
-                             h2d_bytes=h2d):
+                             h2d_bytes=h2d) as span:
                 y = fwd(x)
+                span.set(d2h_early_bytes=y.nbytes)
             with tracer.span("engine.wait", trace=trace, tenant=label):
-                y = jax.block_until_ready(y)  # repro: check-ok(lint.host-sync)
+                host = np.asarray(y)  # repro: check-ok(lint.host-sync)
         if spec is not None and spec.kind == "non_finite_output":
-            y = jnp.full_like(y, jnp.nan)      # poison; caught just below
-        # Host-side finiteness guard: np.asarray on a ready CPU array is
-        # zero-copy, and the reduction is microseconds next to the forward.
-        # A poisoned output FAILS the call rather than returning garbage.
+            host = np.full_like(host, np.nan)  # poison; caught just below
+        # A poisoned output FAILS the call rather than returning garbage;
+        # the reduction is microseconds next to the forward.
         if tracer is None:
-            finite = bool(np.isfinite(np.asarray(y)).all())  # repro: check-ok(lint.host-sync)
+            finite = bool(np.isfinite(host).all())
         else:
             with tracer.span("engine.readback", trace=trace, tenant=label,
                              d2h_bytes=y.nbytes):
-                finite = bool(np.isfinite(np.asarray(y)).all())  # repro: check-ok(lint.host-sync)
+                finite = bool(np.isfinite(host).all())
         if not finite:
             t1 = time.perf_counter()
             self.faults += 1

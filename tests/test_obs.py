@@ -49,6 +49,16 @@ def test_disabled_tracer_returns_shared_noop_ctx():
     assert len(tr) == 0
 
 
+def test_span_set_adds_attrs_inside_the_span():
+    tr = Tracer()
+    with tr.span("work", n=1) as sp:
+        sp.set(out_bytes=64)
+    (s,) = tr.spans
+    assert s.attrs == {"n": 1, "out_bytes": 64}
+    with Tracer(enabled=False).span("work") as sp:
+        sp.set(out_bytes=64)             # the shared no-op takes it too
+
+
 def test_tracer_maxlen_drops_and_counts():
     tr = Tracer(maxlen=3)
     for i in range(5):
@@ -571,6 +581,75 @@ def test_edge_forward_jits_have_stable_names(traced_fleet, level, name):
     fwd = eng._fwd if level == 0 else eng._fallback()
     assert fwd.__name__ == name
     assert f"module @jit_{name} " in fwd.lower(x).as_text()
+
+
+# ---------------------------------------------------------------------------
+# Edge output on the host: one blocking wait per request
+# ---------------------------------------------------------------------------
+
+AD = edge.EdgeConfig(name="mlperf_tiny_ad",
+                     dims=(640, 128, 128, 128, 128, 8, 128, 128, 128, 128,
+                           640))
+
+
+@pytest.mark.parametrize("level", [0, 1], ids=["fused", "per_layer"])
+@pytest.mark.parametrize("cfg", [AD, edge.edge_config("tau_select")],
+                         ids=lambda c: c.name)
+def test_edge_infer_returns_the_forward_ready_on_the_host(cfg, level):
+    """The output's copy to the host rides behind the forward: ``infer``
+    returns exactly what the jitted forward computes, as a ready
+    ``jax.Array`` whose host view holds the same bytes."""
+    eng = engine.EdgeEngine(cfg)
+    if level:
+        eng.degrade()
+    x = np.random.default_rng(3).standard_normal(
+        (cfg.batch, cfg.dims[0])).astype(np.float32)
+    ref = np.asarray(jax.jit(lambda v: edge.edge_forward_q8(
+        eng.qparams, cfg, v, x_scale=eng.x_scale, plan=eng.plan,
+        fused=level == 0))(x))
+    y = eng.infer(x)
+    assert isinstance(y, jax.Array) and y.is_ready()
+    host = np.asarray(y)
+    assert host.dtype == ref.dtype and host.shape == ref.shape
+    assert host.tobytes() == ref.tobytes()
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_edge_nonfinite_fault_raises_after_the_one_wait(traced):
+    """The ``non_finite_output`` fault poisons the output once it is on the
+    host, and the guard still fails the call; the next call is clean."""
+    from repro import faults
+    cfg = edge.edge_config("jet_tagger")
+    tr = Tracer()
+    eng = engine.EdgeEngine(cfg, tracer=tr if traced else None)
+    x = np.ones((cfg.batch, cfg.dims[0]), np.float32)
+    eng.infer(x)                                  # warm
+    tr.clear()
+    eng.injector = faults.FaultPlan(faults=(
+        faults.FaultSpec(kind="non_finite_output", tenant=eng.trace_label,
+                         after=0),)).injector()
+    with pytest.raises(faults.NonFiniteOutput):
+        eng.infer(x)
+    assert eng.faults == 1 and eng.calls == 1
+    if traced:
+        names = [s.name for s in tr.spans]
+        assert names == ["engine.dispatch", "engine.wait", "engine.readback",
+                         "fault/non_finite", "infer"]
+    assert np.isfinite(np.asarray(eng.infer(x))).all()
+
+
+def test_edge_dispatch_requests_the_whole_output_early(traced_fleet):
+    """Every traced edge request asks for its output's copy before its
+    first blocking wait: ``d2h_early_bytes`` on ``engine.dispatch`` is the
+    output's size, the same as ``d2h_bytes`` on ``engine.readback``."""
+    router, tr, xs = traced_fleet
+    tr.clear()
+    ys = [router.infer(nid, x) for nid, x in xs.items()]
+    dispatch = tr.by_name("engine.dispatch")
+    readback = tr.by_name("engine.readback")
+    assert [s.attrs["d2h_early_bytes"] for s in dispatch] == \
+        [y.nbytes for y in ys]
+    assert [s.attrs["d2h_bytes"] for s in readback] == [y.nbytes for y in ys]
 
 
 # ---------------------------------------------------------------------------
