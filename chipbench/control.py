@@ -9,7 +9,8 @@ numbers on the same sampled requests
 
 * for what the program served (the lower reading: the largest over the
   seeds);
-* with the control, the float32 reference computed in int4, put in the
+* with the control, the configuration's reference computed one precision
+  step down (for the edge nets, the float32 reference in int4), put in the
   program's place (the upper reading: the smallest over the seeds);
 * for each fault a served cell can have, planted in the program's answers:
   half of every batch left out, another request's answer returned for one
@@ -84,7 +85,7 @@ def main(argv=None) -> int:
 
 
 def readings(cell, seeds, seconds, out=None) -> dict:
-    from chipbench import check, reference
+    from chipbench import check
     from chipbench.records import FAILED
     counter = run.CompileCounter()
     values = {}                     # kind -> number -> [value per seed]
@@ -97,19 +98,19 @@ def readings(cell, seeds, seconds, out=None) -> dict:
         del played
         gc.collect()
         errs = {"program": {}, "control": {}}
+        compare = cell.kind.compare
         kinds = {
-            "program": check.compare(cell.config, seed, samples, sched,
-                                     pools, failed=failed,
-                                     errors=errs["program"]),
-            "control": check.compare(cell.config, seed, samples, sched,
-                                     pools, failed=0,
-                                     replace=reference.forward_int4,
-                                     errors=errs["control"])}
+            "program": compare(cell.config, seed, samples, sched, pools,
+                               failed=failed, errors=errs["program"]),
+            "control": compare(cell.config, seed, samples, sched, pools,
+                               failed=0,
+                               replace=cell.kind.control(cell.config),
+                               errors=errs["control"])}
         for name, broken in (("half_batch", faulted(samples, half_batch)),
                              ("stale_1in8", stale(samples)),
                              ("altered", faulted(samples, altered))):
-            kinds[name] = check.compare(cell.config, seed, broken, sched,
-                                        pools, failed=0)
+            kinds[name] = compare(cell.config, seed, broken, sched, pools,
+                                  failed=0)
         errors[seed] = {k: {t: v.tolist() for t, v in e.items()}
                         for k, e in errs.items()}
         row = {"seed": seed, "correct": {k: check.is_correct(c)

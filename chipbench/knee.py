@@ -67,17 +67,11 @@ def sweep(cell, rates, seconds, seed) -> list[dict]:
     from chipbench import check
     from chipbench.records import OK
     from chipbench.traffic import generate
-    nets = {n["name"]: n for n in cell.config["nets"]}
-    tenants = cell.traffic["tenants"]
-    batch = cell.config["nets"][0]["batch"]
-    pools = generate.input_pool(
-        cell.traffic, {t: nets[t]["dims"][0] for t in tenants}, batch, seed)
+    pools = cell.kind.input_pool(cell.traffic, cell.config, seed)
     driver = run.load_module(run.HERE / "drivers"
                              / f"{cell.traffic['driver']}.py")
-    router = run.build(cell, seed, None)
-    for t in tenants:
-        for x in pools[t][:2]:
-            np.asarray(router.infer(t, x))
+    router = cell.kind.build(cell.config, seed, None)
+    cell.kind.warm(router, pools)
     gc.collect()
     gc.freeze()                     # as run.play does before its window
     rows = []

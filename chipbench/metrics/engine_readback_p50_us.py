@@ -1,6 +1,7 @@
 """Median duration (us) of the program's ``engine.readback`` span: the
-device-to-host copy of the output (``np.asarray``) and the finiteness
-check.  Over the spans that began before the profiler started."""
+finiteness check on the output's host copy, which ``engine.wait`` has
+already brought to the host.  Over the spans that began before the
+profiler started."""
 
 from chipbench import spans
 

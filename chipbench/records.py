@@ -22,3 +22,6 @@ class Records:
     first_profiled: int      # first request issued under the profiler
                              # (the number of requests when none was)
     samples: dict            # tenant index -> {slot: (request, output)}
+    # Set by drivers of token-generating systems, left None by the others:
+    first: np.ndarray | None = None   # first output token on the host
+    tokens: np.ndarray | None = None  # output tokens of the request

@@ -5,25 +5,28 @@
 
 from the root of a checkout on a machine with a TPU.  The cell, its
 configuration and its traffic mix are looked up by name in
-``BENCHMARK.json``; each configuration, traffic mix, driver and metric
-reader is a file of its own under ``chipbench/``, found by that name.
+``BENCHMARK.json``; each configuration, configuration kind, traffic mix,
+driver and metric reader is a file of its own under ``chipbench/``, found
+by that name.
 
 A run:
 
 1. refuses to go on (exit 2, no result) unless JAX runs on a TPU with at
    least the chips the cell asks for;
-2. builds the configuration's nets through the entry users call,
+2. builds the configuration from the seed through the entry users call, as
+   its kind (``chipbench/kinds/<kind>.py``) says: for the edge nets,
    ``Deployment.build(..., target="tpu", machine_model="auto", seed=seed)``
-   and ``dep.serve()`` with its defaults, and warms every shape the cell
+   and ``dep.serve()`` with its defaults; and warms every shape the cell
    sends;
-3. plays the traffic mix for ``--seconds`` through ``Router.infer`` (the
-   driver the mix names), timing each request from its scheduled arrival to
-   its output on the host;
+3. plays the traffic mix for ``--seconds`` through the router (the driver
+   the mix names), timing each request from its scheduled arrival to its
+   output on the host;
 4. with ``--trace 1``, records the program's spans for the whole window and
    a profiler trace of its last fifth, and reads the per-layer metrics from
    them; with ``--trace 0`` it reads the end-to-end metrics;
 5. frees the program, compares a sample of the window's answers, drawn from
-   the seed, with the float32 reference (``chipbench/check.py``), and prints
+   the seed, with the reference the configuration names (its kind's
+   ``compare``; ``chipbench/check.py``), and prints
    each number compared beside its limit as the last lines of standard
    error, then one JSON result line as the last line of standard output.
 """
@@ -48,7 +51,7 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench.loader import load_module  # noqa: E402
+from chipbench.loader import load_kind, load_module  # noqa: E402
 
 # The profiler trace of a traced run; replaced by every traced run.
 TRACE_DIR = HERE / "out" / "trace"
@@ -69,6 +72,7 @@ class Cell:
     name: str
     chips: int
     config: dict             # the configuration file
+    kind: object             # its kind's module (chipbench/kinds/)
     traffic: dict            # the traffic mix file
     end_to_end: list         # BENCHMARK.json metric entries of this cell
     per_layer: list
@@ -89,6 +93,7 @@ def load_cell(workload: str, bench_path=ROOT / "BENCHMARK.json") -> Cell:
         return [m for m in metrics
                 if "workloads" not in m or workload in m["workloads"]]
     return Cell(name=workload, chips=int(w["chips"]), config=config,
+                kind=load_kind(config, HERE / "kinds"),
                 traffic=generate.load(w["traffic"], HERE / "traffic"),
                 end_to_end=mine(bench["end_to_end"]),
                 per_layer=mine(bench["per_layer"]))
@@ -199,7 +204,7 @@ class RunData:
     tenant: object               # np.ndarray: tenant index per request
     tenants: list
     batch: int
-    work: list                   # work.Work per tenant index
+    work: list                   # work.Work per scheduled request
     peak: dict                   # peaks.json entry of this device
     spans: list | None = None    # the program's spans (traced run)
     trace: object = None         # trace.Trace (traced run)
@@ -260,18 +265,6 @@ def sample_slots(sched, k: int, seed: int) -> dict:
     return out
 
 
-def build(cell: Cell, seed: int, tracer):
-    """The cell's nets behind the router users get from ``dep.serve()``."""
-    from repro.deploy import Deployment
-    from repro.models.edge import EdgeConfig
-    nets = [EdgeConfig(name=n["name"], dims=tuple(n["dims"]), act=n["act"],
-                       batch=n["batch"]) for n in cell.config["nets"]]
-    dep = Deployment.build(nets, target="tpu", machine_model="auto",
-                           seed=seed,
-                           trace=tracer if tracer is not None else False)
-    return dep.serve()
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -302,7 +295,6 @@ class Played:
     tracer: object
     sched: object
     pools: dict
-    nets: dict
     batch: int
     records: object
     window_start: float
@@ -314,35 +306,21 @@ class Played:
 
 def play(cell: Cell, seed: int, seconds: float, *, trace: bool,
          counter: CompileCounter) -> Played:
-    """Build the cell's nets from ``seed``, warm every shape the window
-    sends, and play ``seconds`` of the cell's traffic through them."""
-    import numpy as np
-
+    """Build the cell's configuration from ``seed``, warm every shape the
+    window sends, and play ``seconds`` of the cell's traffic through it."""
     from chipbench import check
     from chipbench.traffic import generate
     from repro.obs import Tracer
 
-    nets = {n["name"]: n for n in cell.config["nets"]}
-    tenants = list(cell.traffic["tenants"])
-    missing = [t for t in tenants if t not in nets]
-    if missing:
-        raise KeyError(f"traffic names nets {missing} that the "
-                       f"configuration does not hold")
-    batches = {n["batch"] for n in nets.values()}
-    if len(batches) != 1:
-        raise ValueError("every net of a configuration serves one batch")
+    kind = cell.kind
     sched = generate.schedule(cell.traffic, seconds, seed)
-    pools = generate.input_pool(
-        cell.traffic, {t: nets[t]["dims"][0] for t in tenants},
-        next(iter(batches)), seed)
+    pools = kind.input_pool(cell.traffic, cell.config, seed)
     slots = sample_slots(sched, check.SAMPLES_PER_TENANT, seed)
     driver = load_module(HERE / "drivers" / f"{cell.traffic['driver']}.py")
 
     tracer = Tracer(maxlen=SPAN_CAP) if trace else None
-    router = build(cell, seed, tracer)
-    for t in tenants:                       # every shape the window sends
-        for x in pools[t][:2]:
-            np.asarray(router.infer(t, x))
+    router = kind.build(cell.config, seed, tracer)
+    kind.warm(router, pools)
     router.reset_metrics()
     if tracer is not None:
         tracer.clear()
@@ -367,7 +345,7 @@ def play(cell: Cell, seed: int, seconds: float, *, trace: bool,
     if profile is not None:
         profile.stop()
     return Played(router=router, tracer=tracer, sched=sched,
-                  pools=pools, nets=nets, batch=next(iter(batches)),
+                  pools=pools, batch=kind.batch(cell.config),
                   records=records, window_start=window_start,
                   setup_s=setup_s,
                   compiles=counter.snapshot().get("compiles", 0) - before,
@@ -424,8 +402,8 @@ def run_cell(cell: Cell, args, dev, n_devices: int) -> int:
         seconds=seconds, window_start=played.window_start,
         setup_s=played.setup_s, records=records, tenant=sched.tenant,
         tenants=sched.tenants, batch=played.batch,
-        work=[work_lib.request_work(played.nets[t]["dims"], played.batch)
-              for t in sched.tenants],
+        work=cell.kind.request_work(cell.config, sched, played.pools,
+                                    records),
         peak=(work_lib.peaks(dev.device_kind) if dev.platform == "tpu"
               else None),
         spans=played.tracer.spans if played.tracer is not None else None)
@@ -473,8 +451,8 @@ def run_cell(cell: Cell, args, dev, n_devices: int) -> int:
     pools = played.pools
     del played, run, records
     gc.collect()
-    compared = check.compare(cell.config, seed, samples, sched, pools,
-                             failed=failed)
+    compared = cell.kind.compare(cell.config, seed, samples, sched, pools,
+                                 failed=failed)
     for name, c in compared.items():
         log(f"compared {name} {c['value']} limit {c['limit']}")
     result = {"correct": check.is_correct(compared), "attempted": attempted,
